@@ -114,7 +114,7 @@ def fd_checks(p: dict) -> dict:
     float64 — the same gate tests/test_plan.py enforces."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     data = build_plan_data("steady", slo=p["slo"], objective="p99",
                            overrides=_overrides(p),

@@ -1,8 +1,11 @@
 """FlashAttention-2-style prefill kernel (Pallas, TPU).
 
 Grid (B, H, nQ, nKV) — KV innermost so the (m, l, acc) online-softmax state
-lives in VMEM scratch across KV steps.  GQA is handled in the K/V BlockSpec
-index map (kv_head = q_head // group).  Causal and sliding-window masks are
+lives in VMEM scratch across KV steps.  The kernel runs head-major: the
+wrapper moves the heads axis ahead of the sequence, so every block's last
+two dims are (sequence tile, head_dim) as the TPU's (8, 128) tiling
+requires.  GQA is handled in the K/V BlockSpec index map
+(kv_head = q_head // group).  Causal and sliding-window masks are
 computed from block-local iotas; fully-masked KV blocks are skipped with
 ``pl.when`` (the TPU grid is sequential, so skipping saves real MXU time).
 
@@ -21,6 +24,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 NEG = -1e30
+
+
+def fit_block(block: int, n: int) -> int:
+    """``block`` where it tiles an axis of length ``n`` exactly, else the
+    whole axis (a block may always span the full array dim)."""
+    return block if block <= n and n % block == 0 else n
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -45,10 +54,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         relevant = win_ok if relevant is None else jnp.logical_and(relevant, win_ok)
 
     def _step():
-        q = q_ref[0, :, 0, :].astype(F32) * scale          # (BQ, hd)
-        k = k_ref[0, :, 0, :].astype(F32)                  # (BK, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=F32)  # (BQ, BK)
+        # operands stay in their own dtype (the MXU's bf16 path, f32
+        # accumulation) and the scale applies to the f32 scores, as in
+        # the reference; an f32 operand would be rounded to bf16 there
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32) * scale  # (BQ, BK)
         qp = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         kp = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         ok = jnp.ones_like(s, bool)
@@ -57,15 +68,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window is not None:
             ok &= kp > qp - window
         s = jnp.where(ok, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                                # (BQ, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0, :].astype(F32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[...]
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=F32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
     if relevant is None:
@@ -76,7 +88,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == n_kv - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -88,29 +100,28 @@ def flash_attention(q, k, v, *, causal: bool = True,
     b, s, h, hd = q.shape
     t, kv = k.shape[1], k.shape[2]
     g = h // kv
-    block_q = min(block_q, s)
-    block_k = min(block_k, t)
-    assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
+    block_q, block_k = fit_block(block_q, s), fit_block(block_k, t)
     n_q, n_kv = s // block_q, t // block_k
     grid = (b, h, n_q, n_kv)
 
     kernel = functools.partial(_kernel, causal=causal, window=window,
                                block_q=block_q, block_k=block_k, n_kv=n_kv,
                                scale=hd ** -0.5)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, hd),
+                          lambda b_, h_, qi, ki: (b_, h_, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, hd),
+                           lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b_, h_, qi, ki: (b_, ki, h_ // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b_, h_, qi, ki: (b_, ki, h_ // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), F32),       # m
-            pltpu.VMEM((block_q,), F32),       # l
+            pltpu.VMEM((block_q, 1), F32),     # m
+            pltpu.VMEM((block_q, 1), F32),     # l
             pltpu.VMEM((block_q, hd), F32),    # acc
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

@@ -40,6 +40,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.util import enable_compile_cache
+    enable_compile_cache()
+
     from repro.scenarios.backends import (build_real_engines,
                                           run_experiment_on_real_engines)
 

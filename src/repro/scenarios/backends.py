@@ -15,6 +15,8 @@ def build_real_engines(arch: str, n: int, *, smoke: bool = False,
                        max_new_tokens: int = 4, seed: int = 0):
     """-> (engines, factory, vocab_size): ``n`` warmed real engines plus a
     ``factory(server_id)`` for servers that join mid-scenario."""
+    from repro.sweep.executor import require_device_process
+    require_device_process("real engines")
     import jax
 
     from repro.configs.base import get_config

@@ -202,6 +202,9 @@ def main(argv=None) -> int:
     add_cache_args(ap)
     args = ap.parse_args(argv)
 
+    from repro.util import enable_compile_cache
+    enable_compile_cache()
+
     if args.list:
         from repro import scenarios
         print("sweepable canonical scenarios:")

@@ -27,6 +27,7 @@ per-task runs under any executor/worker count by construction.
 from __future__ import annotations
 
 import multiprocessing
+import os
 import sys
 from concurrent.futures import FIRST_COMPLETED, ProcessPoolExecutor, wait
 from typing import Callable, Optional
@@ -295,6 +296,32 @@ def _log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+#: set in process-executor workers by ``_host_worker_init``
+_IN_POOL_WORKER = False
+
+
+def _host_worker_init() -> None:
+    """Pool initializer.  Sweep workers run host-side runtimes only, so
+    JAX is pinned to the CPU: a worker must never open the accelerator
+    the parent process may hold (a second process on a TPU fails or
+    hangs)."""
+    global _IN_POOL_WORKER
+    _IN_POOL_WORKER = True
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
+def require_device_process(what: str) -> None:
+    """Refuse device work inside a process-executor worker, whose JAX is
+    pinned to the CPU, instead of hanging on the chip or silently
+    serving from the host."""
+    if _IN_POOL_WORKER:
+        raise RuntimeError(f"{what} need the accelerator, which a "
+                           f"process-executor sweep worker may not open; "
+                           f"run this sweep with executor='serial'")
+
+
 def mp_context():
     """Start-method for sweep workers.
 
@@ -402,7 +429,8 @@ def run_sweep(sweep: Sweep, executor: str = "serial",
             note(done, rows[k])
     elif executor == "process":
         with ProcessPoolExecutor(max_workers=workers,
-                                 mp_context=mp_context()) as pool:
+                                 mp_context=mp_context(),
+                                 initializer=_host_worker_init) as pool:
             futs = {pool.submit(run_task, sweep, i, params, rep,
                                 not fail_fast): k
                     for k, i, params, rep in tasks_left}

@@ -393,7 +393,6 @@ def _shard_cells(run, family: str, n_dev: int):
     server axis, so the sharded program is bit-identical to the
     single-device one (a test pins this)."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     cell = PartitionSpec("cells")          # [C, ...] leading cell axis
@@ -410,8 +409,8 @@ def _shard_cells(run, family: str, n_dev: int):
                 (none,) + (seq,) * (n_xs - 1))
     out_specs = ((cell,) * n_carry, (seq,) * n_ys)
     mesh = Mesh(np.array(jax.devices()[:n_dev]), ("cells",))
-    return shard_map(run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 #: cell-padding fills that keep padded (dead) cells NaN-free: no

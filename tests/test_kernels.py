@@ -27,6 +27,7 @@ def _tol(dtype):
     (1, 256, 4, 1, 64, True, 64),
     (2, 128, 6, 2, 96, True, None),
     (1, 512, 2, 2, 128, True, 256),
+    (1, 96, 4, 2, 64, True, None),       # 64 does not tile 96: one block
 ])
 def test_flash_attention(b, s, h, kv, hd, causal, window, dtype):
     ks = jax.random.split(KEY, 3)
@@ -46,6 +47,7 @@ def test_flash_attention(b, s, h, kv, hd, causal, window, dtype):
     (4, 8, 8, 128, 512, None),
     (2, 4, 1, 64, 256, 64),
     (1, 16, 2, 96, 512, None),
+    (2, 4, 2, 64, 192, None),            # 128 does not tile 192: one block
 ])
 def test_decode_attention(b, h, kv, hd, t, window, dtype):
     ks = jax.random.split(KEY, 4)

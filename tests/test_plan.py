@@ -3,7 +3,7 @@ smoothed primitive (finite-difference checks in float64), seeded
 soft-vs-hard forward agreement on the canonical scenarios, the
 rank-plan unification contract, and the planner/sweep integration.
 
-The FD checks run under ``jax.experimental.enable_x64`` and avoid jit
+The FD checks run under ``jax.enable_x64`` and avoid jit
 so central differences resolve at ``eps ~ 1e-5``; the agreement tests
 reuse the vector runtime's reparameterized draws, so hard and soft
 modes see the SAME noise and the tolerances below are deterministic
@@ -19,7 +19,7 @@ import pytest
 jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
+from jax import enable_x64  # noqa: E402
 
 from repro.plan import (DEFAULT_BOXES, OBJECTIVES, PlanConfig, PlanError,
                         PlanSpec, analytic_capacity, build_plan_data,

@@ -111,6 +111,29 @@ def test_serial_and_process_executors_identical():
     assert sim.recorder.overall().p99 == row.metrics["p99"]
 
 
+def _report_platform(ctx):
+    import jax
+    raise RuntimeError(f"platform={jax.default_backend()}")
+
+
+def _real_engine_point(ctx):
+    from repro.scenarios.backends import build_real_engines
+    build_real_engines("phi3-mini-3.8b", 1, smoke=True)
+
+
+@pytest.mark.parametrize("factory,needle", [
+    (_report_platform, "platform=cpu"),
+    (_real_engine_point, "executor='serial'"),
+])
+def test_process_workers_stay_off_the_accelerator(factory, needle):
+    """Process-executor workers run with JAX pinned to the CPU, and real
+    engines refuse to start there instead of contending for the chip."""
+    sw = Sweep(name="dev", factory=factory, axes=(Axis("x", (0,)),), reps=1)
+    frame = run_sweep(sw, executor="process", workers=1, progress=None)
+    (row,) = frame.rows
+    assert not row.ok and needle in row.error, row.error
+
+
 def test_poisoned_point_records_error_row():
     """A raising point must not kill the sweep: it records an error row
     while every other (point, rep) completes."""
@@ -261,3 +284,30 @@ def test_cli_file_declaration(tmp_path):
     frame = ResultFrame.from_json(str(tmp_path / "filedecl.json"))
     assert frame.spec["axes"] == {"qps": [120.0]}
     assert frame.rows[0].metrics["n"] > 0
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env):
+    """The CLI mains keep JAX's compile cache at one fixed path inside
+    the checkout, and an explicit JAX_COMPILATION_CACHE_DIR wins."""
+    import os
+
+    import jax
+
+    from repro.util import COMPILE_CACHE_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+    try:
+        got = enable_compile_cache()
+        if env is None:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
