@@ -34,6 +34,7 @@ import numpy as np
 from repro.control import (AdmissionController, CircuitBreaker, ControlLoop,
                            RetryBudget)
 from repro.control.resilience import RESILIENCE_STREAM
+from repro.core import spans
 from repro.core.balancer import POLICIES
 from repro.core.client import ClientConfig, ClientGenerator
 from repro.core.harness import Experiment, build_simulator
@@ -221,6 +222,7 @@ class EngineRuntime(Runtime):
         self.telemetry = MetricsPipeline(self.recorder, interval, slo=slo)
         self.dropped = 0
         self._clock = clock
+        self._t0 = 0.0                 # the clock's reading as run() began
         self._sleep = sleep
         self._rng = np.random.default_rng(seed)
         self._rid = itertools.count()
@@ -436,13 +438,16 @@ class EngineRuntime(Runtime):
             self.recorder.record_failure(t_sub, "failed")
             return
         rid = next(self._rid)
-        n_prompt = ptoks if ptoks > 0 else self.prompt_len
-        n_new = mnew if mnew > 0 else self.max_new_tokens
-        prompt = self._rng.integers(0, self.vocab, size=n_prompt)
-        self._meta[rid] = (cid, t_created, attempt, prev_delay, ptoks, mnew,
-                           handle.server_id)
-        handle.outstanding.add(rid)
-        handle.engine.submit(prompt, n_new, rid)
+        # late_s: how far behind its due instant the loop submits it
+        with spans.span("runtime.submit", req=rid,
+                        late_s=self._clock() - self._t0 - t_sub):
+            n_prompt = ptoks if ptoks > 0 else self.prompt_len
+            n_new = mnew if mnew > 0 else self.max_new_tokens
+            prompt = self._rng.integers(0, self.vocab, size=n_prompt)
+            self._meta[rid] = (cid, t_created, attempt, prev_delay, ptoks,
+                               mnew, handle.server_id)
+            handle.outstanding.add(rid)
+            handle.engine.submit(prompt, n_new, rid)
         rp = self._retry
         if rp is not None:
             if attempt == 0 and self._retry_budget is not None:
@@ -653,20 +658,21 @@ class EngineRuntime(Runtime):
         self._next_control = (self._control.spec.interval * self.time_scale
                               if self._control is not None else None)
         end_wall = self.duration * self.time_scale
-        t0 = self._clock()
+        t0 = self._t0 = self._clock()
         while True:
-            now = self._clock() - t0
-            while inj_idx < len(injections) and \
-                    injections[inj_idx].at * self.time_scale <= now:
-                self._apply_injection(injections[inj_idx],
-                                      now=injections[inj_idx].at
-                                      * self.time_scale)
-                inj_idx += 1
-            self._drain_gauges(now)
-            if self._control is not None:
-                self._control_step(now)
-            self._check_deadlines(now)
-            self._drain_retries(now)
+            with spans.span("runtime.tick"):
+                now = self._clock() - t0
+                while inj_idx < len(injections) and \
+                        injections[inj_idx].at * self.time_scale <= now:
+                    self._apply_injection(injections[inj_idx],
+                                          now=injections[inj_idx].at
+                                          * self.time_scale)
+                    inj_idx += 1
+                self._drain_gauges(now)
+                if self._control is not None:
+                    self._control_step(now)
+                self._check_deadlines(now)
+                self._drain_retries(now)
             admitted = False
             while heap and heap[0][0] <= now:
                 t_arr, cid, ptoks, mnew = heapq.heappop(heap)
@@ -710,12 +716,14 @@ class EngineRuntime(Runtime):
             for handle in list(self.handles.values()):
                 if handle.failed or handle.engine.idle():
                     continue
-                completions = handle.engine.step()
+                with spans.span("runtime.step"):
+                    completions = handle.engine.step()
                 stepped = True
                 if completions:
-                    wall = self._clock() - t0
-                    for comp in completions:
-                        self._complete(handle, comp, wall)
+                    with spans.span("runtime.complete"):
+                        wall = self._clock() - t0
+                        for comp in completions:
+                            self._complete(handle, comp, wall)
             if not admitted and not stepped:
                 # nothing in flight: sleep the whole gap to the next due
                 # event (arrival, injection, gauge, or the horizon)
@@ -740,7 +748,8 @@ class EngineRuntime(Runtime):
                 wait = min(targets) - now
                 if self._meta:
                     wait = min(wait, 0.001)
-                self._sleep(max(wait, 1e-6))
+                with spans.span("runtime.sleep"):
+                    self._sleep(max(wait, 1e-6))
         # close out the idle tail: sample every remaining interval up to
         # the scenario horizon (the fleet is quiescent, so these read the
         # same as they would have in real time)

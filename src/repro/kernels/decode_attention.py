@@ -107,5 +107,6 @@ def decode_attention(q, k, v, *, lengths, key_positions=None, q_pos=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention",        # the op's name in a profiler trace
     )(meta, qg, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), kpos)
     return out.reshape(b, h, hd)

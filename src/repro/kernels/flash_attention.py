@@ -123,5 +123,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, hd), F32),    # acc
         ],
         interpret=interpret,
+        name="flash_attention",        # the op's name in a profiler trace
     )(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)))
     return out.transpose(0, 2, 1, 3)

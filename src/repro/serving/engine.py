@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ATTN_SWA, MAMBA, ArchConfig
+from repro.core import spans
 from repro.core.profiles import apply_service_noise
 from repro.models import param as P
 from repro.models import registry as R
@@ -32,6 +33,7 @@ class Request:
     prompt: np.ndarray             # (L,) int32
     max_new_tokens: int
     submitted_at: float = 0.0
+    queued_at: float = 0.0         # on the spans' clock
     prefilled_at: Optional[float] = None
     tokens_out: list = field(default_factory=list)
 
@@ -267,7 +269,7 @@ class InferenceEngine:
     # ------------------------------------------------------------------ api
     def submit(self, prompt: np.ndarray, max_new_tokens: int, req_id: int):
         req = Request(req_id, np.asarray(prompt, np.int32), max_new_tokens,
-                      submitted_at=self.clock())
+                      submitted_at=self.clock(), queued_at=spans.now())
         self.queue.append(req)
 
     def pending(self) -> int:
@@ -282,10 +284,13 @@ class InferenceEngine:
     def step(self) -> list[Completion]:
         """One scheduler iteration. Prefill-priority continuous batching."""
         done: list[Completion] = []
+        n = self.n_active()
         if self.queue and None in self.active:
-            self._admit(self.queue.pop(0), self.active.index(None))
-        elif self.n_active():
-            done = self._decode_once()
+            with spans.span("engine.step", kind="prefill", active=n):
+                self._admit(self.queue.pop(0), self.active.index(None))
+        elif n:
+            with spans.span("engine.step", kind="decode", active=n):
+                done = self._decode_once(n)
         return done
 
     def run_until_idle(self, max_steps: int = 100_000) -> list[Completion]:
@@ -309,20 +314,27 @@ class InferenceEngine:
     def _admit(self, req: Request, slot: int):
         L = len(req.prompt)
         bucket = L if self._exact_prefill else min(_bucket(L), self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :L] = req.prompt           # right-pad; pads masked via positions
-        logits, cache1, pos1 = self._prefill_fn(bucket)(
-            self.params, jnp.asarray(toks), jnp.asarray([L], np.int32))
-        first = int(jnp.argmax(logits[0]))
-        req.tokens_out.append(first)
-        req.prefilled_at = self.clock()
-        self.cache = jax.tree_util.tree_map(
-            lambda c, p: c.at[:, slot].set(p[:, 0].astype(c.dtype)), self.cache, cache1)
-        self.positions = self.positions.at[slot].set(int(pos1[0]))
-        self.tokens = self.tokens.at[slot].set(first)
-        self.active[slot] = req
-        self.prefill_count += 1
-        self._maybe_finish(slot)
+        spans.mark("engine.queue", req.queued_at, spans.now(), req=req.req_id)
+        with spans.span("engine.admit", req=req.req_id, tokens=L,
+                        bucket=bucket):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :L] = req.prompt       # right-pad; pads masked via positions
+            with spans.span("engine.prefill.dispatch"):
+                logits, cache1, pos1 = self._prefill_fn(bucket)(
+                    self.params, jnp.asarray(toks), jnp.asarray([L], np.int32))
+            with spans.span("engine.prefill.sync"):
+                first = int(jnp.argmax(logits[0]))
+            req.tokens_out.append(first)
+            req.prefilled_at = self.clock()
+            with spans.span("engine.admit.insert"):
+                self.cache = jax.tree_util.tree_map(
+                    lambda c, p: c.at[:, slot].set(p[:, 0].astype(c.dtype)),
+                    self.cache, cache1)
+                self.positions = self.positions.at[slot].set(int(pos1[0]))
+                self.tokens = self.tokens.at[slot].set(first)
+            self.active[slot] = req
+            self.prefill_count += 1
+            self._maybe_finish(slot)
 
     def _decode_impl(self, cache, params, tokens, positions):
         logits, new_cache = R.decode_step(self.cfg, params, cache, tokens,
@@ -330,21 +342,25 @@ class InferenceEngine:
                                           moe_impl=self._moe_impl)
         return jnp.argmax(logits, -1).astype(jnp.int32), new_cache
 
-    def _decode_once(self) -> list[Completion]:
-        next_tokens, self.cache = self._decode(self.cache, self.params,
-                                               self.tokens, self.positions)
-        self.positions = self.positions + 1
-        self.tokens = next_tokens
-        self.decode_steps += 1
-        toks = np.asarray(next_tokens)
-        done = []
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            req.tokens_out.append(int(toks[slot]))
-            c = self._maybe_finish(slot)
-            if c:
-                done.append(c)
+    def _decode_once(self, n_active: int) -> list[Completion]:
+        with spans.span("engine.decode", active=n_active):
+            with spans.span("engine.decode.dispatch"):
+                next_tokens, self.cache = self._decode(
+                    self.cache, self.params, self.tokens, self.positions)
+            self.positions = self.positions + 1
+            self.tokens = next_tokens
+            self.decode_steps += 1
+            with spans.span("engine.decode.sync"):
+                toks = np.asarray(next_tokens)
+            done = []
+            with spans.span("engine.decode.emit"):
+                for slot, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    req.tokens_out.append(int(toks[slot]))
+                    c = self._maybe_finish(slot)
+                    if c:
+                        done.append(c)
         return done
 
     def _maybe_finish(self, slot: int) -> Optional[Completion]:

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and each test worker imports every
 test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -56,12 +58,20 @@ def _compiled_text(sharding, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_names(text):
+    """Names of the Mosaic kernels in a compiled module: what a profiler
+    trace calls their ops."""
+    return set(re.findall(
+        r"%([A-Za-z0-9_\-]+?)(?:\.\d+)?\s= [^\n]*tpu_custom_call", text))
+
+
 def test_flash_attention_phi3(one_chip):
     s = 512
     qkv = [((1, s, PHI3_H, PHI3_HD), BF16)] * 3
     text = _compiled_text(one_chip, lambda q, k, v: fa.flash_attention(
         q, k, v, causal=True), *qkv)
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"flash_attention"}
 
 
 def test_decode_attention_phi3(one_chip):
@@ -74,6 +84,7 @@ def test_decode_attention_phi3(one_chip):
         ((b, t, PHI3_H, PHI3_HD), BF16), ((b,), I32), ((b, t), I32),
         ((b,), I32))
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"decode_attention"}
 
 
 def test_ssd_scan_mamba2(one_chip):
