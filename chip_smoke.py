@@ -150,7 +150,8 @@ def serving_phase(seed: int) -> None:
 
     def decode(impl, cache, tok, pos):
         return jax.jit(lambda p, c, t, q: R.decode_step(
-            cfg, p, c, t, q, impl=impl)[0])(params, cache, tok, pos)
+            cfg, p, c, t, q, cache_layouts=R.decode_layouts(cache),
+            impl=impl)[0])(params, cache, tok, pos)
 
     ref_logits, ref_cache, pos = prefill("ref")
     pal_logits, _, _ = prefill("pallas")

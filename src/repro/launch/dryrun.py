@@ -140,7 +140,9 @@ def build_cell(cfg: ArchConfig, cell: ShapeCell, mesh, strategy: str):
     t_sh = named_sharding((cell.global_batch,), ("batch",), mesh, arules)
 
     def step(params, cache, tokens, positions):
-        return R.decode_step(cfg, params, cache, tokens, positions, impl="ref")
+        # abstract arguments have no layout at rest: the compiler picks it
+        return R.decode_step(cfg, params, cache, tokens, positions,
+                             cache_layouts=None, impl="ref")
 
     return (step, (aparams, d["cache"], d["tokens"], d["positions"]),
             (p_sh, c_sh, t_sh, t_sh), None, (1,))

@@ -95,28 +95,70 @@ def make_kv_cache_specs(cfg: ArchConfig, batch: int, max_len: int,
     }
 
 
-def decode_self_attention(cfg: ArchConfig, p: dict, x: jax.Array, cache: dict, *,
-                          positions: jax.Array, lengths: jax.Array,
-                          window: Optional[int] = None, impl: str = "auto"):
-    """One-token decode with cache update.  x: (B, D); positions: (B,)."""
-    b = x.shape[0]
+def _write_rows(leaf, rows, layer, slot):
+    """Write ``rows[b]`` at ``[layer, b, slot[b]]`` of a stacked leaf in
+    place, one dynamic-update-slice per batch slot (a scatter makes the TPU
+    compiler relayout the whole stack around it), then read the layer.
+    -> (the stack, the layer's slice)."""
+    for i in range(rows.shape[0]):
+        start = (layer, i, slot[i]) + (0,) * (rows.ndim - 1)
+        leaf = jax.lax.dynamic_update_slice(leaf, rows[i][None, None, None],
+                                            start)
+    return leaf, jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
+def _write_layer(leaf, rows, layer, slot):
+    """``_write_rows`` as one write of the whole layer.  The CPU compiler
+    widens a bf16 dynamic-update-slice's whole operand to f32, so there
+    each row written would cost a pass over the stack."""
+    old = jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+    hit = jnp.arange(old.shape[1])[None, :] == slot[:, None]
+    new = jnp.where(hit.reshape(hit.shape + (1,) * (old.ndim - 2)),
+                    rows[:, None], old)
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, layer, 0), new
+
+
+def _write_token(leaf, rows, layer, slot):
+    """The new token's rows into a stacked leaf, by the platform compiled
+    for: each writer costs the other platform a multiple of its time (on
+    the TPU a whole-layer write is one more pass over the layer).
+    -> (the stack, the layer's slice with the rows in it)."""
+    return jax.lax.platform_dependent(leaf, rows.astype(leaf.dtype), layer,
+                                      slot, cpu=_write_layer,
+                                      default=_write_rows)
+
+
+def decode_self_attention(cfg: ArchConfig, p: dict, x: jax.Array, cache: dict,
+                          layer: jax.Array, *, positions: jax.Array,
+                          lengths: jax.Array, window: Optional[int] = None,
+                          impl: str = "auto"):
+    """One-token decode against the cache of every layer group.
+
+    x: (B, D); positions: (B,).  ``cache`` leaves are stacked over groups
+    (``k``/``v``: (G, B, T, KV, hd), ``pos``: (G, B, T)); this layer writes
+    only its new token, one row per batch slot at ``[layer, b, positions %
+    T]`` (a ring for SWA), so a donated stack is updated in place.  It then
+    attends over its own slice, new token included.  Returns the output and
+    the stack with the rows written; other leaves pass through.
+    """
     q, k, v = _proj_qkv(cfg, p, x[:, None, :])          # (B,1,H,hd)
     q = rope(q, positions[:, None], cfg.rope_theta, cfg.rope_fraction)[:, 0]
     k = rope(k, positions[:, None], cfg.rope_theta, cfg.rope_fraction)[:, 0]
     v = v[:, 0]
-    size = cache["k"].shape[1]
-    slot = positions % size                              # ring for SWA, id for full
-    bidx = jnp.arange(b)
-    new_k = cache["k"].at[bidx, slot].set(k.astype(cache["k"].dtype))
-    new_v = cache["v"].at[bidx, slot].set(v.astype(cache["v"].dtype))
-    new_pos = cache["pos"].at[bidx, slot].set(positions)
-    new_k = shard(new_k, "batch", "kv_seq", "kv_heads", "head_dim")
-    new_v = shard(new_v, "batch", "kv_seq", "kv_heads", "head_dim")
+    slot = positions % cache["k"].shape[2]               # ring for SWA, id for full
+    kv_axes = ("kv_seq", "kv_heads", "head_dim")
+    k_all, k_l = _write_token(cache["k"], k, layer, slot)
+    v_all, v_l = _write_token(cache["v"], v, layer, slot)
+    pos_all, pos_l = _write_token(cache["pos"], positions, layer, slot)
+    new_cache = dict(cache,
+                     k=shard(k_all, "layer", "batch", *kv_axes),
+                     v=shard(v_all, "layer", "batch", *kv_axes),
+                     pos=shard(pos_all, "layer", "batch", "kv_seq"))
     from repro.kernels import ops
-    o = ops.decode_attention(q, new_k, new_v, lengths=lengths,
-                             key_positions=new_pos, q_pos=positions,
+    o = ops.decode_attention(q, shard(k_l, "batch", *kv_axes),
+                             shard(v_l, "batch", *kv_axes), lengths=lengths,
+                             key_positions=pos_l, q_pos=positions,
                              window=window, impl=impl)
-    new_cache = {"k": new_k, "v": new_v, "pos": new_pos}
     return _out_proj(p, o), new_cache
 
 

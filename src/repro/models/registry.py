@@ -158,17 +158,27 @@ def prefill(cfg: ArchConfig, params: dict, batch: dict, max_len: int, *,
     return logits, cache, lengths
 
 
+def decode_layouts(cache: dict) -> dict:
+    """The layouts a cache's arrays have at rest, for ``decode_step``."""
+    return jax.tree_util.tree_map(lambda c: c.format.layout, cache)
+
+
 def decode_step(cfg: ArchConfig, params: dict, cache: dict, tokens, positions, *,
-                impl: str = "auto", moe_impl: str = "dispatch",
+                cache_layouts, impl: str = "auto", moe_impl: str = "dispatch",
                 enc_lengths=None):
-    """tokens: (B,), positions: (B,) -> (logits (B,V), new cache)."""
+    """tokens: (B,), positions: (B,) -> (logits (B,V), new cache).
+
+    The cache is updated in place when donated.  ``cache_layouts``: its
+    leaves' layouts at rest, or None (see ``transformer.run_stack_decode``);
+    ``decode_layouts`` reads them off the arrays."""
     x = embed_tokens(cfg, params["embed"], tokens)
     if cfg.enc_dec and enc_lengths is None:
         # full encoder context by default (benchmarks)
         enc_len = cache["pos0"]["ek"].shape[2]
         enc_lengths = jnp.full((tokens.shape[0],), enc_len, jnp.int32)
     x, new_cache = T.run_stack_decode(cfg, params["groups"], x, cache,
-                                      positions=positions, impl=impl,
+                                      positions=positions,
+                                      cache_layouts=cache_layouts, impl=impl,
                                       moe_impl=moe_impl, enc_lengths=enc_lengths)
     x = apply_norm(cfg, params["final_norm"], x)
     return unembed(cfg, params, x), new_cache

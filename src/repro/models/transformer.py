@@ -6,6 +6,10 @@ jamba: 7 mamba + 1 attn, MoE on odd positions).  Params and caches are
 stacked along a leading "layer" axis so HLO size is O(|pattern|), not
 O(num_layers) — this keeps 512-device compiles fast and is how real JAX
 frameworks (MaxText et al.) scale depth.
+
+Decode carries the whole stacked cache through the group scan and updates
+it in place: each layer writes only its new token (or its recurrent state)
+at its group index, so a step never builds a second cache.
 """
 from __future__ import annotations
 
@@ -13,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import with_layout_constraint
 
 from repro.configs.base import ATTN, ATTN_SWA, ENC_ATTN, MAMBA, ArchConfig
 from repro.distributed.sharding import shard
@@ -103,30 +108,41 @@ def apply_block_seq(cfg: ArchConfig, p: dict, kind: str, x: jax.Array, *,
 
 
 def apply_block_decode(cfg: ArchConfig, p: dict, kind: str, x: jax.Array,
-                       cache: dict, *, positions: jax.Array, impl: str,
-                       moe_impl: str, enc_lengths=None):
-    """x: (B, D) single token."""
+                       cache: dict, layer: jax.Array, *, positions: jax.Array,
+                       impl: str, moe_impl: str, enc_lengths=None):
+    """x: (B, D) single token.  ``cache``: this position's leaves stacked
+    over groups; ``layer`` is the group index.  Returns the stack with the
+    block's new entries written at ``layer``."""
     h = apply_norm(cfg, p["norm1"], x)
     if kind == MAMBA:
-        mix, new_cache = mamba_mod.decode_mamba(cfg, p["mamba"], h, cache)
+        mix, state = mamba_mod.decode_mamba(cfg, p["mamba"], h,
+                                            _layer_slice(cache, layer))
+        cache = {n: jax.lax.dynamic_update_index_in_dim(
+                     c, state[n].astype(c.dtype), layer, 0)
+                 for n, c in cache.items()}
     else:
         window = cfg.sliding_window if kind == ATTN_SWA else None
-        mix, new_cache = attn_mod.decode_self_attention(
-            cfg, p["attn"], h, cache, positions=positions,
+        mix, cache = attn_mod.decode_self_attention(
+            cfg, p["attn"], h, cache, layer, positions=positions,
             lengths=positions + 1, window=window, impl=impl)
     if cfg.parallel_block:
-        return x + mix + _ffn(cfg, p, h, moe_impl), new_cache
+        return x + mix + _ffn(cfg, p, h, moe_impl), cache
     x = x + mix
     if "xattn" in p:
         hx = apply_norm(cfg, p["xnorm"], x)
+        enc = _layer_slice({"ek": cache["ek"], "ev": cache["ev"]}, layer)
         x = x + attn_mod.cross_attention_decode(cfg, p["xattn"], hx,
-                                                cache["ek"], cache["ev"],
+                                                enc["ek"], enc["ev"],
                                                 enc_lengths, impl=impl)
-        new_cache = dict(new_cache, ek=cache["ek"], ev=cache["ev"])
     if "norm2" in p:
         h2 = apply_norm(cfg, p["norm2"], x)
         x = x + _ffn(cfg, p, h2, moe_impl)
-    return x, new_cache
+    return x, cache
+
+
+def _layer_slice(cache: dict, layer: jax.Array) -> dict:
+    return {n: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+            for n, c in cache.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -237,21 +253,41 @@ def _mamba_prefill_cache(cfg, p, hn):
 
 
 def run_stack_decode(cfg: ArchConfig, groups: dict, x: jax.Array, cache: dict, *,
-                     positions: jax.Array, impl: str = "auto",
+                     positions: jax.Array, cache_layouts, impl: str = "auto",
                      moe_impl: str = "dispatch", pattern=None, enc_lengths=None):
+    """One decode token through every group.
+
+    The stacked cache rides the scan's carry; the scan runs over the group
+    parameters and the group index.  Each block writes only its new entries
+    at its group (one K/V row and position per batch slot for attention,
+    the recurrent state for mamba), so a donated cache is updated in place
+    and no second cache is built.  Cross-attention K/V are only read.
+
+    ``cache_layouts`` (a tree of ``jax.experimental.layout.Layout`` like
+    ``cache``, from each leaf's ``format.layout``) holds the carry to the
+    layouts the cache has at rest; the TPU's default layout of a leaf
+    depends on its shape, so only the holder of the arrays knows it.
+    ``None`` leaves the carry's layout to the compiler: the TPU compiler
+    then gives it the layout the attention kernel reads and converts the
+    whole stack into it before the loop and back after it.
+    """
     pattern = pattern or cfg.resolved_pattern
+    n_groups = jax.tree_util.tree_leaves(groups)[0].shape[0]
+    pin = ((lambda c: with_layout_constraint(c, cache_layouts))
+           if cache_layouts is not None else (lambda c: c))
 
     def group_fn(carry, xs):
-        gp, gcache = xs
-        h = carry
-        new_caches = {}
+        h, cache = carry
+        gp, layer = xs
+        cache = dict(cache)
         for i, kind in enumerate(pattern):
-            h, nc = apply_block_decode(cfg, gp[f"pos{i}"], kind, h,
-                                       gcache[f"pos{i}"], positions=positions,
-                                       impl=impl, moe_impl=moe_impl,
-                                       enc_lengths=enc_lengths)
-            new_caches[f"pos{i}"] = nc
-        return h, new_caches
+            key = f"pos{i}"
+            h, cache[key] = apply_block_decode(
+                cfg, gp[key], kind, h, cache[key], layer, positions=positions,
+                impl=impl, moe_impl=moe_impl, enc_lengths=enc_lengths)
+        return (h, pin(cache)), None
 
-    x, new_cache = jax.lax.scan(group_fn, x, (groups, cache), unroll=cost_mode())
-    return x, new_cache
+    (x, cache), _ = jax.lax.scan(group_fn, (x, cache),
+                                 (groups, jnp.arange(n_groups)),
+                                 unroll=cost_mode())
+    return x, cache

@@ -253,6 +253,8 @@ class InferenceEngine:
             R.cache_specs(cfg, max_batch, max_len, enc_len=enc_len),
             jax.random.PRNGKey(0))  # repro: noqa[seed-convention] —
         # fixed key: cache init allocates zeroed buffers, never samples
+        # the decode step updates the cache in place, in these layouts
+        self._cache_layouts = R.decode_layouts(self.cache)
         self.positions = jnp.zeros((max_batch,), jnp.int32)
         self.tokens = jnp.zeros((max_batch,), jnp.int32)
         self.active: list[Optional[Request]] = [None] * max_batch
@@ -338,7 +340,9 @@ class InferenceEngine:
 
     def _decode_impl(self, cache, params, tokens, positions):
         logits, new_cache = R.decode_step(self.cfg, params, cache, tokens,
-                                          positions, impl=self._impl,
+                                          positions,
+                                          cache_layouts=self._cache_layouts,
+                                          impl=self._impl,
                                           moe_impl=self._moe_impl)
         return jnp.argmax(logits, -1).astype(jnp.int32), new_cache
 

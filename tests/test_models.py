@@ -97,7 +97,8 @@ def test_decode_matches_forward(arch):
     logits, cache, pos = R.prefill(cfg, params, batch, max_len=S + STEPS + 8)
     for i in range(STEPS):
         tok = toks[:, S + i]
-        logits, cache = R.decode_step(cfg, params, cache, tok, pos)
+        logits, cache = R.decode_step(cfg, params, cache, tok, pos,
+                                      cache_layouts=R.decode_layouts(cache))
         pos = pos + 1
         fb = dict(batch)
         fb["tokens"] = jnp.concatenate([batch["tokens"], toks[:, S:S + i + 1]], 1)
