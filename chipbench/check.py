@@ -2,18 +2,17 @@
 
 After the window, a sample of the finished requests, drawn from the seed
 and holding the one with the most served tokens, is run once through the
-plain float32 reference over its prompt and served tokens.  The number
-compared is the widest gap by which a served (greedy) token's reference
-logit lies below the reference's best logit at that position: zero where
-the program picked the reference's own argmax, small where rounding
-swapped two near-equal logits, large where the program computed
+plain float32 reference that the configuration names
+(``chipbench/references/<name>.py``) over its prompt and served tokens.
+The number compared is the widest gap by which a served (greedy) token's
+reference logit lies below the reference's best logit at that position:
+zero where the program picked the reference's own argmax, small where
+rounding swapped two near-equal logits, large where the program computed
 something else.
 """
 from __future__ import annotations
 
 import numpy as np
-
-from chipbench import reference as REF
 
 
 def sample(finished: list, seed: int, served_tokens: int,
@@ -63,22 +62,23 @@ def _batch(reqs: list) -> tuple:
     return tokens, rows, served, valid
 
 
-def logit_gaps(config: dict, seed: int, reqs: list,
+def logit_gaps(ref, config: dict, seed: int, reqs: list,
                control: bool = False) -> dict:
-    """Widest reference-logit gap of the served tokens; with ``control``
-    also that of the tokens the float8 forward puts first."""
+    """Widest logit gap of the served tokens in the reference module
+    ``ref``; with ``control`` also that of the tokens its control (the
+    precision below the configuration's) puts first."""
     tokens, rows, served, valid = _batch(reqs)
-    ref = np.asarray(REF.logits_at(config, seed, tokens, rows))
-    best = ref.max(-1)
+    logits = np.asarray(ref.logits_at(config, seed, tokens, rows))
+    best = logits.max(-1)
 
     def widest(picked):
-        got = np.take_along_axis(ref, picked[..., None], -1)[..., 0]
+        got = np.take_along_axis(logits, picked[..., None], -1)[..., 0]
         return float(np.max(np.where(valid, best - got, 0.0)))
 
     out = {"max_logit_gap": widest(served), "served_tokens": int(valid.sum()),
            "requests": len(reqs)}
     if control:
-        low = np.asarray(REF.logits_at(config, seed, tokens, rows,
+        low = np.asarray(ref.logits_at(config, seed, tokens, rows,
                                        control=True))
         out["control_max_logit_gap"] = widest(low.argmax(-1).astype(np.int32))
     return out

@@ -13,6 +13,14 @@ precision, so on a TPU they are float32 and not bfloat16.
 every linear layer, each row of activations and each output column of
 weights scaled to the format's range: the step below the bfloat16 that
 the served configuration states.
+
+A configuration file names its reference (``"reference": "dense"``); the
+harness finds it as ``chipbench/references/<name>.py``.  Every reference
+module provides what the harness reads (``run.REFERENCE_API``): ``dims``,
+``logits_at``, ``smoke_file``, ``check_program`` and ``leaf_rules``.  It
+also holds the model counts that its metric files read through
+``run.ref``: here ``prefill_flops`` and ``decode_flops``, with the
+(flops, bytes) of each kernel it names.
 """
 from __future__ import annotations
 
@@ -23,9 +31,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import weights as W
+from chipbench.counts import (decode_attention, decode_flops,  # noqa: F401
+                              flash_attention, prefill_flops)
 
 F32 = jnp.float32
 _E4M3_MAX = 448.0
+
+#: weight rules by path suffix; ``weights.init_rule``'s own rules cover
+#: every leaf of this family
+leaf_rules: dict = {}
 
 
 def dims(cfg: dict) -> dict:
@@ -43,6 +57,39 @@ def dims(cfg: dict) -> dict:
         "rotary": int(hd * cfg.get("partial_rotary_factor", 1.0)) // 2 * 2,
         "window": cfg.get("sliding_window"),
     }
+
+
+def smoke_file(c) -> dict:
+    """The configuration file's keys at the sizes of the program's config
+    ``c`` (its -smoke config, for a rehearsal on the CPU)."""
+    out = {"num_hidden_layers": c.num_layers, "hidden_size": c.d_model,
+           "num_attention_heads": c.num_heads,
+           "num_key_value_heads": c.num_kv_heads, "head_dim": c.head_dim,
+           "intermediate_size": c.d_ff, "vocab_size": c.vocab_size,
+           "rope_theta": c.rope_theta,
+           "partial_rotary_factor": c.rope_fraction}
+    # the program fixes its norm epsilon at 1e-6
+    out["layer_norm_eps" if c.norm == "layernorm" else "rms_norm_eps"] = 1e-6
+    return out
+
+
+def check_program(c, cfg: dict) -> dict:
+    """-> {field: (program's value, file's value)} where the program's
+    config ``c`` is not the one the configuration file states."""
+    want = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
+            "num_heads": "num_attention_heads",
+            "num_kv_heads": "num_key_value_heads", "d_ff": "intermediate_size",
+            "vocab_size": "vocab_size", "resolved_head_dim": "head_dim",
+            "rope_theta": "rope_theta"}
+    bad = {a: (getattr(c, a), cfg[k]) for a, k in want.items()
+           if getattr(c, a) != cfg[k]}
+    frac = cfg.get("partial_rotary_factor", 1.0)
+    if c.rope_fraction != frac:
+        bad["rope_fraction"] = (c.rope_fraction, frac)
+    norm = "layernorm" if "layer_norm_eps" in cfg else "rmsnorm"
+    if c.norm != norm:
+        bad["norm"] = (c.norm, norm)
+    return bad
 
 
 def _leaves(m: dict) -> dict:
@@ -137,14 +184,16 @@ def _block(x, w, mk, control):
 @functools.partial(jax.jit, static_argnames=("mk",))
 def _layer_weights(key, layer, mk):
     m = dict(mk)
-    return {p: W.make_leaf(key, "groups/pos0/" + p, s, F32, layer)
+    return {p: W.make_leaf(key, "groups/pos0/" + p, s, F32, layer,
+                           leaf_rules)
             for p, s in _leaves(m).items()}
 
 
 @functools.partial(jax.jit, static_argnames=("mk",))
 def _embed(key, tokens, mk):
     m = dict(mk)
-    table = W.make_leaf(key, "embed/tokens", (m["vocab"], m["d"]), F32)
+    table = W.make_leaf(key, "embed/tokens", (m["vocab"], m["d"]), F32,
+                        rules=leaf_rules)
     return table[tokens]
 
 
@@ -153,10 +202,11 @@ def _logits(key, x, rows, mk, control):
     """Logits at ``rows`` (N, P) of each sequence of ``x`` (N, T, d)."""
     m = dict(mk)
     d = m["d"]
-    fs = W.make_leaf(key, "final_norm/scale", (d,), F32)
-    fb = (W.make_leaf(key, "final_norm/bias", (d,), F32)
+    fs = W.make_leaf(key, "final_norm/scale", (d,), F32, rules=leaf_rules)
+    fb = (W.make_leaf(key, "final_norm/bias", (d,), F32, rules=leaf_rules)
           if m["layernorm"] else None)
-    head = W.make_leaf(key, "unembed/kernel", (m["vocab"], d), F32)
+    head = W.make_leaf(key, "unembed/kernel", (m["vocab"], d), F32,
+                       rules=leaf_rules)
     h = jnp.take_along_axis(x, rows[..., None], axis=1)      # (N, P, d)
     h = _norm(m, h, fs, fb)
     return _mm(h, head.T, control)

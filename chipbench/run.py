@@ -16,7 +16,8 @@ Everything that belongs to one configuration, traffic mix or metric is a
 file of its own, found by the name in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<traffic>.json``,
 ``checks/<workload>.json`` (the limits of the correctness check) and
-``metrics/<metric>.py``.
+``metrics/<metric>.py``; the configuration file names its plain reference,
+with its weight rules and model counts, ``references/<reference>.py``.
 """
 from __future__ import annotations
 
@@ -26,16 +27,23 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import faulthandler  # noqa: E402
+import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Optional  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+REFERENCES = os.path.join(HERE, "references")
+#: what the harness reads of every reference module (its model counts,
+#: such as ``decode_flops``, are read by metric files of its own)
+REFERENCE_API = ("dims", "logits_at", "smoke_file", "check_program",
+                 "leaf_rules")
 for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
@@ -60,6 +68,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    ref: ModuleType
 
 
 def load_cell(name: str) -> Cell:
@@ -69,22 +78,49 @@ def load_cell(name: str) -> Cell:
         fail(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = load_json(os.path.join(ROOT, conf["file"]))
+    ref_name = config.get("reference")
+    if not (isinstance(ref_name, str) and ref_name.isidentifier()
+            and os.path.isfile(os.path.join(REFERENCES, f"{ref_name}.py"))):
+        fail(f"configuration {conf['name']!r} ({conf['file']}) names "
+             f"reference {ref_name!r}: there is no chipbench/references/"
+             f"{ref_name}.py")
+    ref = reference(ref_name)
+    missing = [a for a in REFERENCE_API if not hasattr(ref, a)]
+    if missing:
+        fail(f"reference {ref_name!r} lacks {', '.join(missing)}")
 
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
-    return Cell(name, w, load_json(os.path.join(ROOT, conf["file"])),
+    return Cell(name, w, config,
                 load_json(os.path.join(HERE, "traffic", w["traffic"] + ".json")),
                 load_json(os.path.join(HERE, "checks", name + ".json")),
-                mine(bench["end_to_end"]), mine(bench["per_layer"]))
+                mine(bench["end_to_end"]), mine(bench["per_layer"]), ref)
+
+
+def _module(path: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(name: str, where: Optional[str] = None) -> ModuleType:
+    """The reference module ``<where>/<name>.py`` (``where`` defaults to
+    ``REFERENCES``), loaded once a file."""
+    return _reference(os.path.abspath(
+        os.path.join(where or REFERENCES, name + ".py")))
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(path: str) -> ModuleType:
+    name = os.path.splitext(os.path.basename(path))[0]
+    return _module(path, "chipbench_reference_" + name)
 
 
 def reader(metric: str):
-    path = os.path.join(HERE, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(os.path.join(HERE, "metrics", metric + ".py"),
+                   "chipbench_metric_" + metric.replace(".", "_")).read
 
 
 def device_check(chips: int) -> dict:
@@ -114,6 +150,8 @@ class Run:
     peak: dict
     trace: Optional[dict] = None
     trace_s: float = 0.0
+    #: the configuration's reference module, with its model counts
+    ref: Optional[ModuleType] = None
 
 
 def measure(cell: Cell, *, seed: int, seconds: float, trace: bool,
@@ -125,7 +163,6 @@ def measure(cell: Cell, *, seed: int, seconds: float, trace: bool,
     breaks the engine after warm-up; ``control`` also reads the float8
     control's gap and judges it by the same limit (``control_correct``)."""
     from chipbench import check as CHK
-    from chipbench import reference as REF
     from chipbench import serve as S
     from chipbench import trace as TRC
 
@@ -136,12 +173,14 @@ def measure(cell: Cell, *, seed: int, seconds: float, trace: bool,
     config = smoke_config or cell.config
     res = S.run_serving(cell.config, cell.traffic, seed=seed,
                         seconds=seconds, trace_dir=trace_dir,
-                        smoke=smoke_config is not None, fault=fault,
+                        ref=cell.ref, smoke=smoke_config is not None,
+                        fault=fault,
                         t_start=T_START if t_start is None else t_start)
     rec = res["rec"]
     run = Run(seconds=seconds, window=res["window"], t0=rec.t0,
               arrivals=rec.arrivals, reqs=rec.reqs, steps=rec.steps,
-              setup_s=res["setup_s"], model=REF.dims(config), peak=peak)
+              setup_s=res["setup_s"], model=cell.ref.dims(config),
+              peak=peak, ref=cell.ref)
     out: dict = {"memory_peak_bytes": res["memory_peak_bytes"],
                  "compiles_in_window": res["compiles_in_window"],
                  "buckets": res["buckets"]}
@@ -178,7 +217,7 @@ def measure(cell: Cell, *, seed: int, seconds: float, trace: bool,
                         chk["served_tokens"], chk["max_requests"])
     S.note(t_start, f"checking {len(sample)} requests against the reference")
     t = time.perf_counter()
-    gaps = CHK.logit_gaps(config, seed, sample, control=control) \
+    gaps = CHK.logit_gaps(cell.ref, config, seed, sample, control=control) \
         if sample else {"max_logit_gap": float("inf")}
     out["check_s"] = time.perf_counter() - t
     out.update(attempted=len(submitted), finished=len(finished),

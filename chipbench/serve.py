@@ -279,10 +279,12 @@ def free(engine) -> None:
     gc.collect()
 
 
-def build(config: dict, traffic: dict, *, seed: int, smoke: bool = False):
-    """Weights from the seed (one jitted call on the device), the engine
-    sized for the traffic, and every shape the traffic uses compiled or
-    loaded from the compile cache.  -> (engine, bucket_of)."""
+def build(config: dict, traffic: dict, *, seed: int, ref,
+          smoke: bool = False):
+    """Weights from the seed (one jitted call on the device, by the
+    reference module ``ref``'s weight rules), the engine sized for the
+    traffic, and every shape the traffic uses compiled or loaded from the
+    compile cache.  -> (engine, bucket_of)."""
     import jax
 
     from chipbench import weights as W
@@ -292,10 +294,13 @@ def build(config: dict, traffic: dict, *, seed: int, smoke: bool = False):
 
     cfg = get_config(config["program_arch"] + ("-smoke" if smoke else ""))
     if not smoke:
-        check_program_config(cfg, config)
+        bad = ref.check_program(cfg, config)
+        if bad:
+            raise SystemExit(f"program config {cfg.name} differs from the "
+                             f"configuration file: {bad}")
     p_max, n_max = TR.max_lengths(traffic)
     max_len = p_max + n_max + 32
-    params = W.make_tree(R.abstract_params(cfg), seed)
+    params = W.make_tree(R.abstract_params(cfg), seed, rules=ref.leaf_rules)
     jax.block_until_ready(params)
     eng = E.InferenceEngine(cfg, params,
                             max_batch=config["assumed"]["max_batch"],
@@ -345,14 +350,14 @@ def serve_window(engine, traffic: dict, *, seed: int, seconds: float,
 
 
 def run_serving(config: dict, traffic: dict, *, seed: int,
-                seconds: float, trace_dir: Optional[str], smoke: bool = False,
-                fault=None, t_start: float) -> dict:
+                seconds: float, trace_dir: Optional[str], ref,
+                smoke: bool = False, fault=None, t_start: float) -> dict:
     """Set up, run the window, and hand back everything the metric readers
     and the check need.  The engine's state is freed before returning."""
     import jax
 
     note(t_start, "building the engine")
-    eng, bucket_of = build(config, traffic, seed=seed, smoke=smoke)
+    eng, bucket_of = build(config, traffic, seed=seed, ref=ref, smoke=smoke)
     note(t_start, "weights made; warming up")
     ramp = traffic["ramp_s"]
     buckets = warm_buckets(eng, TR.schedule(traffic, ramp + seconds + 1.0),
@@ -369,26 +374,6 @@ def run_serving(config: dict, traffic: dict, *, seed: int,
     return {"rec": rec, "window": (w0, w1), "setup_s": w0 - t_start,
             "buckets": buckets, "memory_peak_bytes": peak,
             "compiles_in_window": n_compiles}
-
-
-def check_program_config(cfg, config: dict) -> None:
-    """The program's configuration has to be the one the file states."""
-    want = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
-            "num_heads": "num_attention_heads",
-            "num_kv_heads": "num_key_value_heads", "d_ff": "intermediate_size",
-            "vocab_size": "vocab_size", "resolved_head_dim": "head_dim",
-            "rope_theta": "rope_theta"}
-    bad = {a: (getattr(cfg, a), config[k]) for a, k in want.items()
-           if getattr(cfg, a) != config[k]}
-    frac = config.get("partial_rotary_factor", 1.0)
-    if cfg.rope_fraction != frac:
-        bad["rope_fraction"] = (cfg.rope_fraction, frac)
-    norm = "layernorm" if "layer_norm_eps" in config else "rmsnorm"
-    if cfg.norm != norm:
-        bad["norm"] = (cfg.norm, norm)
-    if bad:
-        raise SystemExit(f"program config {cfg.name} differs from the "
-                         f"configuration file: {bad}")
 
 
 class CompileCounter:

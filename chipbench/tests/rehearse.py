@@ -12,18 +12,11 @@ from repro.configs.base import get_config
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
-def smoke_file(arch: str) -> dict:
-    """The configuration file's keys at the program's -smoke sizes."""
-    c = get_config(arch + "-smoke")
-    out = {"num_hidden_layers": c.num_layers, "hidden_size": c.d_model,
-           "num_attention_heads": c.num_heads,
-           "num_key_value_heads": c.num_kv_heads, "head_dim": c.head_dim,
-           "intermediate_size": c.d_ff, "vocab_size": c.vocab_size,
-           "rope_theta": c.rope_theta,
-           "partial_rotary_factor": c.rope_fraction}
-    # the program fixes its norm epsilon at 1e-6
-    out["layer_norm_eps" if c.norm == "layernorm" else "rms_norm_eps"] = 1e-6
-    return out
+def smoke_file(config: dict) -> dict:
+    """A configuration file's keys at the program's -smoke sizes, as the
+    reference the file names writes them."""
+    ref = RUN.reference(config["reference"])
+    return ref.smoke_file(get_config(config["program_arch"] + "-smoke"))
 
 
 def small(traffic: dict, served_tokens: int = 40) -> dict:
@@ -50,5 +43,5 @@ def rehearse(workload: str, *, seconds: float = 3.0, trace: bool = False,
         cell.traffic["rate_per_s"] = rate
     return RUN.measure(cell, seed=seed, seconds=seconds, trace=trace,
                        peak=PEAK, fault=fault, control=control,
-                       smoke_config=smoke_file(cell.config["program_arch"]),
+                       smoke_config=smoke_file(cell.config),
                        t_start=time.perf_counter())

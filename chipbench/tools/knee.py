@@ -40,7 +40,8 @@ def main() -> int:
     RUN.device_check(1)
     enable_compile_cache()
     cell = RUN.load_cell(args.workload)
-    eng, bucket_of = S.build(cell.config, cell.traffic, seed=args.seed)
+    eng, bucket_of = S.build(cell.config, cell.traffic, seed=args.seed,
+                             ref=cell.ref)
     rates = [float(r) for r in args.rates.split(",")]
     rows = []
     horizon = cell.traffic["ramp_s"] + args.seconds + 1.0

@@ -34,12 +34,39 @@ def _leaf_key(key, path: str, layer):
     return k if layer is None else jax.random.fold_in(k, layer)
 
 
-def init_rule(path: str, shape: tuple) -> tuple:
+def _exponent(fan: int) -> int:
+    """Power of two that gives odd integers an RMS about 1/sqrt(fan)."""
+    return round(math.log2(1.0 / math.sqrt(fan) / _ODD_RMS))
+
+
+def _declared(path: str, rules: dict):
+    """The rule of the longest suffix in ``rules`` that ends ``path`` at a
+    "/" (or is the whole path), or None."""
+    hits = [s for s in rules if path == s or path.endswith("/" + s)]
+    return rules[max(hits, key=len)] if hits else None
+
+
+def init_rule(path: str, shape: tuple, rules=None) -> tuple:
     """-> (kind, exponent) for a leaf of one layer (unstacked ``shape``).
 
-    kind "matrix": odd * 2**e with RMS about 1/sqrt(fan_in) (the embedding
+    ``rules``, a reference's ``leaf_rules``, maps a path suffix
+    ("moe/wi_0") to a kind and the axes whose product is the fan-in
+    (``("matrix", (1,))``); it is looked up first.  Otherwise: kind
+    "matrix": odd * 2**e with RMS about 1/sqrt(fan_in) (the embedding
     table: RMS about 1); "scale": 1 + odd * 2**-11; "bias": odd * 2**-11.
-    An unknown path is an error: the served tree's layout has changed."""
+    An unknown path is an error: the served tree's layout has changed, or
+    its reference declares no rule for it.  So is a bank of matrices
+    (a 3-D ``wi_0``, ``wi_1`` or ``wo``) with no declared rule: which axis
+    is the fan-in is not for this function to guess."""
+    rule = _declared(path, rules or {})
+    if rule is not None:
+        kind, axes = rule
+        if kind in ("scale", "bias"):
+            return kind, -11
+        if kind != "matrix":
+            raise ValueError(f"rule {rule!r} for leaf {path!r} has no "
+                             f"kind 'matrix', 'scale' or 'bias'")
+        return "matrix", _exponent(math.prod(shape[a] for a in axes))
     name = path.rsplit("/", 1)[-1]
     if name == "scale":
         return "scale", -11
@@ -49,19 +76,24 @@ def init_rule(path: str, shape: tuple) -> tuple:
         fan = 1
     elif path == "unembed/kernel":
         fan = shape[1]
+    elif name in ("wi_0", "wi_1", "wo") and len(shape) > 2:
+        raise ValueError(f"leaf {path!r} of shape {shape} is a bank of "
+                         f"matrices: its reference has to declare its "
+                         f"fan-in axes in leaf_rules")
     elif name in ("q", "k", "v", "wi_0", "wi_1", "wo"):
         fan = shape[0]
     elif name == "o":
         fan = shape[0] * shape[1]
     else:
         raise ValueError(f"no initialisation rule for leaf {path!r}")
-    return "matrix", round(math.log2(1.0 / math.sqrt(fan) / _ODD_RMS))
+    return "matrix", _exponent(fan)
 
 
-def make_leaf(key, path: str, shape: tuple, dtype, layer=None) -> jax.Array:
+def make_leaf(key, path: str, shape: tuple, dtype, layer=None,
+              rules=None) -> jax.Array:
     """One layer's values of leaf ``path`` (``shape`` without the layer
-    axis), traceable."""
-    kind, e = init_rule(path, shape)
+    axis), traceable; ``rules`` as ``init_rule`` takes them."""
+    kind, e = init_rule(path, shape, rules)
     n = math.prod(shape)
     # four random bytes from each 32-bit word
     words = jax.random.bits(_leaf_key(key, path, layer), (-(-n // 4),),
@@ -79,11 +111,11 @@ def _paths(tree) -> list:
     return ["/".join(str(getattr(k, "key", k)) for k in p) for p, _ in flat]
 
 
-def make_tree(abstract, seed: int):
+def make_tree(abstract, seed: int, rules=None):
     """Materialise the served tree from its abstract shapes in one jitted
-    call on the default device.  Leaves under "groups" are stacked over
-    layers (leading axis) and made one layer at a time (``lax.map``), so
-    that the call holds little beyond its output."""
+    call on the default device, by the reference's ``rules``.  Leaves under
+    "groups" are stacked over layers (leading axis) and made one layer at a
+    time (``lax.map``), so that the call holds little beyond its output."""
     leaves, treedef = jax.tree_util.tree_flatten(abstract)
     paths = _paths(abstract)
 
@@ -94,9 +126,10 @@ def make_tree(abstract, seed: int):
             if path.startswith("groups/"):
                 layers = jnp.arange(leaf.shape[0], dtype=jnp.uint32)
                 out.append(jax.lax.map(lambda i, p=path, s=leaf: make_leaf(
-                    key, p, s.shape[1:], s.dtype, i), layers))
+                    key, p, s.shape[1:], s.dtype, i, rules), layers))
             else:
-                out.append(make_leaf(key, path, leaf.shape, leaf.dtype))
+                out.append(make_leaf(key, path, leaf.shape, leaf.dtype,
+                                     rules=rules))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return jax.jit(build)(jax.random.key_data(seed_key(seed)))
